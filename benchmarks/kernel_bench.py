@@ -16,11 +16,12 @@ import numpy as np
 
 from benchmarks.common import save_result
 from repro.kernels import ref
-from repro.roofline import HW
+from repro.roofline import PEAKS, V5E
 
 
 def roofline_time(nbytes: float, flops: float) -> float:
-    return max(nbytes / HW["hbm_bw"], flops / HW["peak_flops"])
+    hw = PEAKS[V5E]
+    return max(nbytes / hw["hbm_bw"], flops / hw["peak_flops"])
 
 
 def run():

@@ -87,6 +87,11 @@ class CollectiveProbe(Probe):
         self._bytes = np.empty(0, dtype=np.float64)
         self._base_lat = np.empty(0, dtype=np.float64)
 
+    @property
+    def schedule_ops(self) -> List[str]:
+        """Collective ops of the registered program, in HLO order."""
+        return [rec["op"] for rec in self._schedule]
+
     def register_compiled(self, hlo_text: str) -> None:
         """Read the collective schedule off a compiled artifact (non-intrusive)."""
         import json

@@ -23,18 +23,22 @@ import psutil
 
 from repro.core.events import Layer
 from repro.core.probes.base import Probe
+from repro.roofline import device_peaks
 
 
 class TpuTelemetryModel:
     """Telemetry simulator for one device: first-order thermal/power model."""
 
-    def __init__(self, peak_flops: float = 197e12, hbm_gb: float = 16.0,
+    def __init__(self, peak_flops: Optional[float] = None,
+                 hbm_gb: Optional[float] = None,
                  idle_w: float = 60.0, peak_w: float = 250.0,
                  ambient_c: float = 30.0, seed: int = 0):
         import random
 
-        self.peak_flops = peak_flops
-        self.hbm_gb = hbm_gb
+        peaks = device_peaks()
+        self.peak_flops = peaks["peak_flops"] if peak_flops is None \
+            else peak_flops
+        self.hbm_gb = peaks["hbm_gib"] if hbm_gb is None else hbm_gb
         self.idle_w = idle_w
         self.peak_w = peak_w
         self.temp_c = ambient_c

@@ -55,13 +55,6 @@ class JaxRuntimeProbe(Probe):
         jax.monitoring.register_event_listener(on_event)
 
     def _detach(self) -> None:
-        # jax.monitoring has module-level listener lists; de-register by removal.
-        from jax._src import monitoring as _mon
-
-        for lst_name in ("_event_duration_secs_listeners", "_event_listeners"):
-            lst = getattr(_mon, lst_name, None)
-            if lst is not None:
-                for target in (self._dur_listener, self._evt_listener):
-                    while target in lst:
-                        lst.remove(target)
+        jax.monitoring.unregister_event_duration_listener(self._dur_listener)
+        jax.monitoring.unregister_event_listener(self._evt_listener)
         self._dur_listener = self._evt_listener = None

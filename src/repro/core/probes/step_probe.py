@@ -16,6 +16,7 @@ import jax
 
 from repro.core.events import Layer
 from repro.core.probes.base import Probe
+from repro.roofline import device_peaks
 
 
 class StepProbe(Probe):
@@ -23,13 +24,15 @@ class StepProbe(Probe):
 
     def __init__(self, operator_probe=None, collective_probe=None,
                  device_probe=None, flops_per_step: float = 0.0,
-                 peak_flops: float = 197e12, mem_gb_per_step: float = 0.0):
+                 peak_flops: Optional[float] = None,
+                 mem_gb_per_step: float = 0.0):
         super().__init__()
         self.operator_probe = operator_probe
         self.collective_probe = collective_probe
         self.device_probe = device_probe
         self.flops_per_step = flops_per_step
-        self.peak_flops = peak_flops
+        self.peak_flops = (device_peaks()["peak_flops"] if peak_flops is None
+                           else peak_flops)
         self.mem_gb_per_step = mem_gb_per_step
         self.step_count = 0
         self.extra_latency = 0.0  # chaos hook: python-layer delay (real sleep)

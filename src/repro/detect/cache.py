@@ -13,13 +13,14 @@ signature — a miss means a fresh XLA compile on the sweep that saw it — and
 those counts feed the ``eacgm_detect_compile_*`` self-metrics.
 
 `enable_persistent_cache` opts into JAX's on-disk compilation cache so the
-first sweep of a *process* doesn't pay the compile either (best-effort: older
-jax versions without the config knob just ignore it).
+first sweep of a *process* doesn't pay the compile either.
 """
 from __future__ import annotations
 
+import os
+import pathlib
 import threading
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import numpy as np
 
@@ -81,29 +82,25 @@ class ShapeBucketCache:
 # same way every jit call shares one XLA executable cache.
 SHAPE_CACHE = ShapeBucketCache()
 
-_persistent_dir: Optional[str] = None
+# Fixed and in the checkout: a cache directory that moves between runs (a
+# temporary, pid- or time-derived path) is never found again.
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def enable_persistent_cache(cache_dir: str) -> bool:
-    """Point JAX's on-disk compilation cache at ``cache_dir`` (idempotent).
+def enable_persistent_cache() -> str:
+    """Turn on JAX's on-disk compilation cache; returns its directory.
 
-    Returns True if the knob exists and was set. With it, shape-bucket
-    misses cost a cache *read* instead of a compile from the second process
-    onwards — the persistent half of making sweeps kernel-cheap."""
-    global _persistent_dir
-    if _persistent_dir == cache_dir:
-        return True
-    try:
-        import jax
+    ``JAX_COMPILATION_CACHE_DIR``, when set, places the cache (JAX reads it
+    itself, so no directory is set here); otherwise it is the checkout's
+    ``.jax_cache``. Launchers call this once at start-up; tests never do.
+    With it, shape-bucket misses and the step program cost a cache *read*
+    instead of a compile from the second process onwards."""
+    import jax
 
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = str(DEFAULT_CACHE_DIR)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # compile anything that takes longer than this to cache (default 1s
-        # skips exactly the small GMM kernels we care about)
-        try:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception:
-            pass
-        _persistent_dir = cache_dir
-        return True
-    except Exception:
-        return False
+    # the default floor (1 s) would skip exactly the small GMM kernels
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir

@@ -12,9 +12,12 @@ the final grid block: the same single pass over X returns the *updated*
 means and covariances (plus nk and the data log-likelihood), so one EM
 iteration is exactly one kernel launch + a tiny (K, D, D) host-side Cholesky.
 
-Both kernels take an ``nvalid`` row count so callers can pad N to a fixed
-power-of-two bucket (see `repro.detect.cache`) and reuse one compiled
-executable across the sliding-window sizes a streaming detector sees.
+Both kernels take an ``nvalid`` row count (a (1, 1) int32 in SMEM) so
+callers can pad N to a fixed power-of-two bucket (see `repro.detect.cache`)
+and reuse one compiled executable across the sliding-window sizes a
+streaming detector sees. As in `gmm_score`, every value stays 2-D with D or
+K on the lanes and the loops over K are static: Mosaic refuses reshapes
+that split or merge the lane dimension.
 
 The grid dimension over N-blocks is sequential on TPU, so the accumulator
 pattern (init at program_id==0, += afterwards, finalise at the last block)
@@ -26,38 +29,28 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-LOG2PI = float(np.log(2.0 * np.pi))
+from repro.kernels.gmm_score import (HIGHEST, component_terms, for_each_chunk,
+                                     log_densities)
+
+
+def _t_dot(a, b):
+    """a^T @ b for (bn, P) x (bn, Q) -> (P, Q), f32 on the MXU."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               precision=HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _accumulate_estep(i, x_ref, logw_ref, mu_u_ref, u_ref, logdet_ref,
                       nvalid_ref, nk_ref, sx_ref, sxx_ref, ll_ref):
-    """Shared E-step body: accumulate (nk, sx, sxx, ll) for one N-block."""
-    x = x_ref[...].astype(jnp.float32)  # (bn, D)
-    u = u_ref[...].astype(jnp.float32)  # (K, D, D)
-    K, D, _ = u.shape
-    bn = x.shape[0]
+    """Shared E-step body: accumulate (nk, sx, sxx, ll) for one N-block.
 
-    xu = jax.lax.dot_general(
-        x, u.transpose(1, 0, 2).reshape(D, K * D),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(bn, K, D)
-    z = xu - mu_u_ref[...][None].astype(jnp.float32)
-    logp = (-0.5 * (D * LOG2PI + jnp.sum(z * z, axis=-1))
-            + logdet_ref[...][None].astype(jnp.float32))  # (bn, K)
-    logr = logp + logw_ref[...][None].astype(jnp.float32)
-    m = jnp.max(logr, axis=-1, keepdims=True)
-    norm = m + jnp.log(jnp.sum(jnp.exp(logr - m), axis=-1, keepdims=True))
-    resp = jnp.exp(logr - norm)  # (bn, K)
-
-    # mask padding rows (global row id >= nvalid)
-    row = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
-    valid = (row < nvalid_ref[0]).astype(jnp.float32)
-    resp = resp * valid
-    norm = norm * valid
+    nk is kept as a (K, 1) column and ll as (1, 1) so the M-step can
+    broadcast them against (K, D) rows without a lane/sublane relayout."""
+    bn = x_ref.shape[0]
+    K = u_ref.shape[0]
 
     @pl.when(i == 0)
     def _init():
@@ -66,15 +59,28 @@ def _accumulate_estep(i, x_ref, logw_ref, mu_u_ref, u_ref, logdet_ref,
         sxx_ref[...] = jnp.zeros_like(sxx_ref)
         ll_ref[...] = jnp.zeros_like(ll_ref)
 
-    nk_ref[...] += jnp.sum(resp, axis=0)
-    # (K, bn) @ (bn, D) on the MXU
-    sx_ref[...] += jax.lax.dot_general(resp, x, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-    rx = resp[:, :, None] * x[:, None, :]  # (bn, K, D)
-    sxx_ref[...] += jax.lax.dot_general(
-        rx.reshape(bn, K * D), x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).reshape(K, D, D)
-    ll_ref[...] += jnp.sum(norm)
+    def chunk(r, rows):
+        x = x_ref[pl.ds(r, rows), :].astype(jnp.float32)  # (rows, D)
+        logr = (log_densities(x, mu_u_ref, u_ref, logdet_ref)
+                + logw_ref[...])  # (rows, K)
+        m = jnp.max(logr, axis=-1, keepdims=True)
+        norm = m + jnp.log(jnp.sum(jnp.exp(logr - m), axis=-1,
+                                   keepdims=True))
+        resp = jnp.exp(logr - norm)  # (rows, K)
+
+        # mask padding rows (global row id >= nvalid)
+        row = i * bn + r + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        valid = (row < nvalid_ref[0, 0]).astype(jnp.float32)
+        resp = resp * valid
+        norm = norm * valid
+
+        nk_ref[...] += _t_dot(resp, jnp.ones((rows, 1), jnp.float32))
+        sx_ref[...] += _t_dot(resp, x)  # (K, D)
+        for k in range(K):
+            sxx_ref[k] += _t_dot(resp[:, k:k + 1] * x, x)  # (D, D)
+        ll_ref[...] += jnp.sum(norm, axis=0, keepdims=True)
+
+    for_each_chunk(bn, chunk)
 
 
 def _stats_kernel(x_ref, logw_ref, mu_u_ref, u_ref, logdet_ref, nvalid_ref,
@@ -94,11 +100,16 @@ def _update_kernel(x_ref, logw_ref, mu_u_ref, u_ref, logdet_ref, nvalid_ref,
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _m_step():
-        nk = nk_ref[...] + 1e-10
-        mu = mean_ref[...] / nk[:, None]
-        cov = cov_ref[...] / nk[:, None, None] - mu[:, :, None] * mu[:, None, :]
+        # 1/nk materialised over (K, D): row k then broadcasts down the
+        # sublanes of (D, D). A broadcast of the (K, 1) column itself would
+        # leave a lane-replicated layout that Mosaic cannot broadcast again
+        inv = (1.0 / (nk_ref[...] + 1e-10)) * jnp.ones(mean_ref.shape,
+                                                        jnp.float32)
+        mu = mean_ref[...] * inv  # (K, D)
         mean_ref[...] = mu
-        cov_ref[...] = cov
+        for k in range(mu.shape[0]):
+            mu_k = mu[k:k + 1]  # (1, D)
+            cov_ref[k] = cov_ref[k] * inv[k:k + 1] - _t_dot(mu_k, mu_k)
 
 
 def _prepare(X, means, prec_chol, nvalid, block_n):
@@ -108,13 +119,10 @@ def _prepare(X, means, prec_chol, nvalid, block_n):
     pad = n_blocks * block_n - N
     if pad:
         X = jnp.pad(X, ((0, pad), (0, 0)))
-    mu_u = jnp.einsum("kd,kde->ke", means.astype(jnp.float32),
-                      prec_chol.astype(jnp.float32))
-    logdet = jnp.sum(jnp.log(jnp.abs(
-        jnp.diagonal(prec_chol, axis1=-2, axis2=-1))), axis=-1)
+    mu_u, logdet = component_terms(means, prec_chol)
     if nvalid is None:
         nvalid = N
-    nvalid = jnp.asarray(nvalid, jnp.int32).reshape(1)
+    nvalid = jnp.asarray(nvalid, jnp.int32).reshape(1, 1)
     return X, mu_u, logdet, nvalid, n_blocks
 
 
@@ -122,22 +130,25 @@ def _launch(kernel, X, log_weights, mu_u, prec_chol, logdet, nvalid,
             n_blocks, block_n, interpret):
     K, D = mu_u.shape
     full = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
-    return pl.pallas_call(
+    nk, sx, sxx, ll = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((block_n, D), lambda i: (i, 0)),
-            full(K), full(K, D), full(K, D, D), full(K), full(1),
+            full(1, K), full(K, D), full(K, D, D), full(1, K),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=[full(K), full(K, D), full(K, D, D), full(1)],
+        out_specs=[full(K, 1), full(K, D), full(K, D, D), full(1, 1)],
         out_shape=[
-            jax.ShapeDtypeStruct((K,), jnp.float32),
+            jax.ShapeDtypeStruct((K, 1), jnp.float32),
             jax.ShapeDtypeStruct((K, D), jnp.float32),
             jax.ShapeDtypeStruct((K, D, D), jnp.float32),
-            jax.ShapeDtypeStruct((1,), jnp.float32),
+            jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(X, log_weights, mu_u, prec_chol, logdet, nvalid)
+    )(X, log_weights.astype(jnp.float32).reshape(1, K), mu_u, prec_chol,
+      logdet, nvalid)
+    return nk[:, 0], sx, sxx, ll[0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -149,9 +160,8 @@ def gmm_stats_pallas(X, log_weights, means, prec_chol, *, nvalid=None,
     zero-padded X with the true row count to reuse one compiled shape."""
     X, mu_u, logdet, nvalid, n_blocks = _prepare(X, means, prec_chol,
                                                  nvalid, block_n)
-    nk, sx, sxx, ll = _launch(_stats_kernel, X, log_weights, mu_u, prec_chol,
-                              logdet, nvalid, n_blocks, block_n, interpret)
-    return nk, sx, sxx, ll[0]
+    return _launch(_stats_kernel, X, log_weights, mu_u, prec_chol, logdet,
+                   nvalid, n_blocks, block_n, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -163,6 +173,5 @@ def gmm_update_pallas(X, log_weights, means, prec_chol, *, nvalid=None,
     O(K D^2) host work against one kernel launch."""
     X, mu_u, logdet, nvalid, n_blocks = _prepare(X, means, prec_chol,
                                                  nvalid, block_n)
-    nk, mu, cov, ll = _launch(_update_kernel, X, log_weights, mu_u, prec_chol,
-                              logdet, nvalid, n_blocks, block_n, interpret)
-    return nk, mu, cov, ll[0]
+    return _launch(_update_kernel, X, log_weights, mu_u, prec_chol, logdet,
+                   nvalid, n_blocks, block_n, interpret)

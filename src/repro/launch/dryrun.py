@@ -1,12 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # placeholders only: never take a chip
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape x mesh)
 cell and derive the roofline terms from the compiled artifact.
 
-The two lines above MUST run before any other import (jax locks the device
+The lines above MUST run before any other import (jax locks the device
 count at first backend init); 512 placeholder host devices back both the
-single-pod 16x16 mesh and the 2x16x16 multi-pod mesh.
+single-pod 16x16 mesh and the 2x16x16 multi-pod mesh. The dry run stays on
+the CPU even on a TPU host, so it and the child per-cell processes it
+spawns never hold the chip another process needs.
 
 Usage:
     python -m repro.launch.dryrun --arch llama3.2-1b --shape train_4k

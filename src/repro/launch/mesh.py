@@ -7,17 +7,12 @@ XLA_FLAGS before any jax call).
 from __future__ import annotations
 
 import jax
-try:  # jax >= 0.5: explicit axis types (Auto == GSPMD propagation)
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every axis is implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # Auto axes: GSPMD propagates shardings from the constraints we place
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -20,18 +20,21 @@ pair `benchmarks/serve_bench.py` measures.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.config import get_arch, reduced
+from repro.detect.cache import enable_persistent_cache
 from repro.models.model import Runtime, init_params
-from repro.serve import (ContinuousBatchingEngine, LoadGenerator,
+from repro.serve import (ContinuousBatchingEngine, LoadGenerator, Request,
                          RequestQueue, ServeEngine)
-from repro.session import MonitorSpec, Session, SinkSpec
+from repro.session import MonitorReport, MonitorSpec, Session, SinkSpec
 
 # historical tuning of the serve driver (legacy-flag path only)
 LEGACY_SPEC_DEFAULTS = {
@@ -50,7 +53,22 @@ def _parse_range(arg: str, name: str) -> tuple:
     return (parts[0], parts[1])
 
 
+@dataclasses.dataclass
+class ServeRun:
+    """What one `run` produced: the exit code `main` returns, plus the
+    served requests a caller may check (the smoke test on the chip does)."""
+
+    exit_code: int
+    requested: int  # --num-requests (0 = a --steps horizon)
+    finished: List[Request]  # continuous engine only
+    report: Optional[MonitorReport]  # None when monitoring is off
+
+
 def main(argv=None) -> int:
+    return run(argv).exit_code
+
+
+def run(argv=None) -> ServeRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt2")
     ap.add_argument("--reduced", action="store_true")
@@ -96,13 +114,14 @@ def main(argv=None) -> int:
                     help="write a live HTML status board here "
                          "(= a \"board\" sink)")
     args = ap.parse_args(argv)
+    enable_persistent_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
     if not cfg.has_decode:
         print(f"{cfg.name} is encoder-only: no decode step")
-        return 0
+        return ServeRun(0, args.num_requests, [], None)
     rt = Runtime(mesh=None, compute_dtype=jnp.float32)
     params = init_params(jax.random.PRNGKey(args.seed), cfg)
 
@@ -121,17 +140,19 @@ def main(argv=None) -> int:
         print(f"[monitor] metrics endpoint: "
               f"{session.sink('prometheus').url}/metrics")
 
+    finished: List[Request] = []
     if args.static_batch:
         rc = _run_static(args, cfg, rt, params, session, spec)
     else:
-        rc = _run_continuous(args, cfg, rt, params, session)
+        rc, finished = _run_continuous(args, cfg, rt, params, session)
+    report = None
     if not session.off:
         report = session.result()
         print(report.render())
-    return rc
+    return ServeRun(rc, args.num_requests, finished, report)
 
 
-def _run_continuous(args, cfg, rt, params, session) -> int:
+def _run_continuous(args, cfg, rt, params, session):
     engine = ContinuousBatchingEngine(
         cfg, rt, params, slots=args.slots, max_len=args.max_len,
         temperature=args.temperature, seed=args.seed)
@@ -210,7 +231,7 @@ def _run_continuous(args, cfg, rt, params, session) -> int:
         if stats:
             print("[monitor] serve:", {k: round(v, 4)
                                        for k, v in sorted(stats.items())})
-    return 0
+    return 0, fin
 
 
 def _run_static(args, cfg, rt, params, session, spec) -> int:

@@ -17,18 +17,23 @@ snapshot).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.config import TrainConfig, get_arch, reduced
+from repro.config import ShapeConfig, TrainConfig, get_arch, reduced
 from repro.data import SyntheticLMData
+from repro.detect.cache import enable_persistent_cache
+from repro.launch import specs as S
 from repro.launch.mesh import make_local_mesh
 from repro.models.model import Runtime
 from repro.roofline import model_flops
-from repro.session import MonitorSpec, Session, SinkSpec
+from repro.session import MonitorReport, MonitorSpec, Session, SinkSpec
 from repro.train.checkpoint import CheckpointManager
 from repro.train.step import (init_train_state, make_optimizer_for,
                               make_train_step)
@@ -39,7 +44,24 @@ LEGACY_PROBE_OPTIONS = {"python": {"sample_every": 25},
                         "device": {"interval": 0.05}}
 
 
+@dataclasses.dataclass
+class TrainRun:
+    """What one `run` produced: the exit code `main` returns, plus the
+    artifacts a caller may check (the smoke test on the chip does)."""
+
+    exit_code: int
+    losses: List[float]
+    state: Any  # final TrainState
+    compiled: Any  # the jax.stages.Compiled step program that ran
+    report: Optional[MonitorReport]  # None when monitoring is off
+    collectives: List[str]  # ops of the collective probe's schedule
+
+
 def main(argv=None) -> int:
+    return run(argv).exit_code
+
+
+def run(argv=None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt2")
     ap.add_argument("--reduced", action="store_true",
@@ -75,6 +97,7 @@ def main(argv=None) -> int:
                          "(= a \"board\" sink)")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_persistent_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -87,14 +110,12 @@ def main(argv=None) -> int:
     tcfg = TrainConfig(learning_rate=args.lr, total_steps=args.steps,
                        optimizer=args.optimizer, warmup_steps=args.steps // 10)
     opt = make_optimizer_for(tcfg)
+    shp = ShapeConfig("run", args.seq, args.batch, "train")
 
     data = SyntheticLMData(cfg, seq_len=args.seq, global_batch=args.batch,
                            seed=args.seed)
     key = jax.random.PRNGKey(args.seed)
     state = init_train_state(key, cfg, opt)
-    step_fn = jax.jit(make_train_step(cfg, rt, opt,
-                                      microbatches=args.microbatches),
-                      donate_argnums=(0,))
 
     # ---- fault tolerance: auto-resume ----
     ckpt = None
@@ -105,6 +126,31 @@ def main(argv=None) -> int:
         if restored is not None:
             state, start_step = restored, rstep
             print(f"[resume] restored checkpoint at step {rstep}")
+
+    # ---- placement + one compile ----
+    # On a mesh the state is placed by the model's partition rules and the
+    # batch is split over the data axis; the step keeps both placements, so
+    # every call runs the one program compiled here (the collective probe
+    # reads this same program's HLO)
+    jit_kw = {}
+    batch_sharding = None
+    if mesh is not None:
+        _, state_specs = S.train_state_specs(cfg, rt, tcfg)
+        state_sharding = S.named(mesh, state_specs)
+        batch_sharding = S.named(mesh, S.batch_pspecs(cfg, shp, rt))
+        state = jax.device_put(state, state_sharding)
+        jit_kw = dict(in_shardings=(state_sharding, batch_sharding),
+                      out_shardings=(state_sharding,
+                                     NamedSharding(mesh, P())))
+
+    def device_batch(step):
+        return jax.device_put(data.batch(step), batch_sharding)
+
+    step_fn = jax.jit(make_train_step(cfg, rt, opt,
+                                      microbatches=args.microbatches),
+                      donate_argnums=(0,), **jit_kw)
+    compiled = step_fn = step_fn.lower(
+        state, device_batch(start_step)).compile()
 
     # ---- monitoring session (runtime attachment; user code unchanged) ----
     # the batch sweep historically fitted with min_events=48; the stream
@@ -133,23 +179,17 @@ def main(argv=None) -> int:
             seed=args.seed)
 
     losses = []
+    collectives: List[str] = []
     t0 = time.time()
     with session.monitoring():
         if not session.off:
-            from repro.config import ShapeConfig
-            shp = ShapeConfig("run", args.seq, args.batch, "train")
-            raw_batch = data.batch(0)
-            lowered = None
-            try:
-                lowered = jax.jit(make_train_step(cfg, rt, opt)).lower(
-                    state, jax.tree.map(jnp.asarray, raw_batch))
-            except Exception:
-                pass
             step_fn = session.observe_step_fn(
-                step_fn, lowered=lowered,
+                step_fn, lowered=compiled,
                 flops_per_step=model_flops(cfg, shp),
                 mem_gb=sum(x.size * x.dtype.itemsize for x in
                            jax.tree.leaves(state.params)) / 2**30)
+            if "collective" in session.spec.probes:
+                collectives = session.collector["collective"].schedule_ops
 
         # ---- training loop ----
         # KeyboardInterrupt is caught INSIDE the monitoring context: the
@@ -159,8 +199,7 @@ def main(argv=None) -> int:
             for step in range(start_step, args.steps):
                 if injector is not None:
                     injector.apply(step, session.collector)
-                batch = jax.tree.map(jnp.asarray, data.batch(step))
-                state, metrics = step_fn(state, batch)
+                state, metrics = step_fn(state, device_batch(step))
                 loss = float(metrics["loss"])
                 losses.append(loss)
                 if step % args.log_every == 0 or step == args.steps - 1:
@@ -196,6 +235,7 @@ def main(argv=None) -> int:
             ckpt.save(start_step + len(losses) - 1, state,
                       meta={"loss": losses[-1]})
         ckpt.close()
+    report = None
     if not session.off:
         report = session.result()
         print(report.render())
@@ -203,7 +243,9 @@ def main(argv=None) -> int:
     if losses:
         print(f"final loss {losses[-1]:.4f} (start {losses[0]:.4f}); "
               f"{len(losses)} steps in {time.time()-t0:.1f}s")
-    return 130 if interrupted else 0
+    return TrainRun(exit_code=130 if interrupted else 0, losses=losses,
+                    state=state, compiled=compiled, report=report,
+                    collectives=collectives)
 
 
 if __name__ == "__main__":

@@ -29,14 +29,6 @@ from repro.config import ModelConfig
 from repro.models.layers import activation, fanin_init
 from repro.models.ffn import init_ffn, ffn_forward
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6: top-level API, check_vma kwarg
-    _shard_map = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-else:  # older jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
 
 def init_moe(key, cfg: ModelConfig) -> Dict[str, Any]:
     ks = jax.random.split(key, 5)
@@ -181,12 +173,12 @@ def moe_dispatch_compute(x_flat, top_idx, top_w, experts, cfg: ModelConfig, rt) 
             model_axis=model_axis)
 
     expert_spec = jax.tree.map(lambda _: P(model_axis), experts)
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(tok, None), P(tok, None), P(tok, None), expert_spec),
         out_specs=P(tok, None),
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )
     return fn(x_flat, top_idx, top_w, experts)
 

@@ -7,27 +7,47 @@
 HLO_FLOPs/bytes come from ``compiled.cost_analysis()`` (the per-device SPMD
 program). Collective bytes are NOT in cost_analysis — they are summed from the
 collective ops' operand sizes in the compiled HLO text (see
-core.probes.collective_probe.parse_hlo_collectives, shared with the monitor).
+repro.hloanalysis).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware constants come from `PEAKS`, keyed by ``device_kind``; the dry
+run models TPU v5e pods, so it reads the v5e entry.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
-import math
 from typing import Any, Dict, Optional
 
-from repro.config import ModelConfig, ShapeConfig, padded_vocab
-from repro.core.probes.collective_probe import (collective_bytes_by_op,
-                                                parse_hlo_collectives)
+from repro.config import ModelConfig, ShapeConfig
 
-HW = {
-    "peak_flops": 197e12,  # bf16 / chip
-    "hbm_bw": 819e9,  # B/s / chip
-    "link_bw": 50e9,  # B/s / ICI link
-    "dcn_bw": 25e9,  # B/s / host cross-pod (multi-pod "pod" axis)
+# Published per-chip peaks, keyed by jax's ``Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GiB HBM at
+# 819 GB/s. link_bw is the bandwidth model's per-link ICI figure, not a
+# published peak.
+V5E = "TPU v5 lite"
+PEAKS: Dict[str, Dict[str, float]] = {
+    V5E: {
+        "peak_flops": 197e12,  # bf16 FLOP/s / chip
+        "hbm_bw": 819e9,  # B/s / chip
+        "hbm_gib": 16.0,
+        "link_bw": 50e9,  # B/s / ICI link
+    },
 }
+
+
+def device_peaks(device_kind: Optional[str] = None) -> Dict[str, float]:
+    """Peaks of ``device_kind``; by default of the chip this process runs
+    on. A kind missing from `PEAKS` is an error, never a default. Off a TPU
+    the probes simulate a v5e, so they get the v5e entry by name."""
+    if device_kind is None:
+        import jax
+
+        dev = jax.devices()[0]
+        device_kind = dev.device_kind if dev.platform == "tpu" else V5E
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 @dataclasses.dataclass
@@ -60,7 +80,7 @@ class RooflineReport:
     @property
     def mfu(self) -> float:
         """Model-FLOPs utilisation at the roofline-estimated step time."""
-        denom = self.step_time_s * self.n_devices * HW["peak_flops"]
+        denom = self.step_time_s * self.n_devices * PEAKS[V5E]["peak_flops"]
         return self.model_flops / denom if denom else 0.0
 
 
@@ -106,9 +126,10 @@ def analyze(*, arch: str, shape_name: str, mesh_desc: str, n_devices: int,
     byts = model.bytes_out
     coll = dict(model.collective_bytes)
     coll_total = sum(coll.values())
-    compute_s = flops / HW["peak_flops"]
-    memory_s = byts / HW["hbm_bw"]
-    collective_s = coll_total / HW["link_bw"]
+    hw = PEAKS[V5E]
+    compute_s = flops / hw["peak_flops"]
+    memory_s = byts / hw["hbm_bw"]
+    collective_s = coll_total / hw["link_bw"]
     mf = model_flops(cfg, shape)
     total_hlo = flops * n_devices
     useful = mf / total_hlo if total_hlo else 0.0

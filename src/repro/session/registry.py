@@ -151,7 +151,8 @@ def _step_probe(opts: Dict[str, Any], peers: Dict[str, Probe]) -> Probe:
     return StepProbe(operator_probe=peers.get("operator"),
                      collective_probe=peers.get("collective"),
                      device_probe=peers.get("device"),
-                     peak_flops=float(opts.get("peak_flops", 197e12)))
+                     peak_flops=(float(opts["peak_flops"])
+                                 if "peak_flops" in opts else None))
 
 
 @register_probe("request")

@@ -99,18 +99,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys
 sys.path.insert(0, "src")
-import contextlib
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.config import get_arch, reduced
 from repro.launch.mesh import make_local_mesh
 from repro.models.model import Runtime, init_params, loss_fn, param_partition_specs
 mesh = make_local_mesh(2, 4)
-def mesh_ctx():
-    # jax >= 0.6 wants the mesh installed via set_mesh; older jax propagates
-    # NamedSharding through GSPMD with no ambient mesh at all
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else \
-        contextlib.nullcontext()
 for arch in ["deepseek-v2-236b", "arctic-480b", "zamba2-7b", "mamba2-2.7b",
              "h2o-danube-3-4b", "hubert-xlarge"]:
     cfg = reduced(get_arch(arch))
@@ -127,7 +121,7 @@ for arch in ["deepseek-v2-236b", "arctic-480b", "zamba2-7b", "mamba2-2.7b",
         batch = {"embeddings": 0.1*jax.random.normal(key, (B,S,cfg.d_model)),
                  "labels": jax.random.randint(key, (B,S), 0, cfg.vocab_size)}
     batch_s = jax.device_put(batch, NamedSharding(mesh, P("data")))
-    with mesh_ctx():
+    with jax.set_mesh(mesh):
         loss_sharded, _ = jax.jit(lambda p,b: loss_fn(p, cfg, rt, b))(params_s, batch_s)
     rt0 = Runtime(mesh=None, compute_dtype=jnp.float32)
     loss_local, _ = jax.jit(lambda p,b: loss_fn(p, cfg, rt0, b))(params, batch)

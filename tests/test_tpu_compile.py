@@ -1,0 +1,88 @@
+"""The GMM kernels compile for a TPU v5e that is described, not attached.
+
+Interpret mode (tests/test_kernels.py) checks the kernels' math; only the
+chip's own compiler (Mosaic) checks that it accepts their layouts, and its
+refusals are what kept the detection plane off the chip. These compiles need
+the TPU compiler, not a chip: the topology is described inside a fixture,
+which skips where it cannot be described.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.gmm_score import gmm_best_pallas, gmm_score_pallas
+from repro.kernels.gmm_stats import gmm_stats_pallas, gmm_update_pallas
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_persistent_cache):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _score(spec, bn):
+    return gmm_score_pallas.lower(spec("X"), spec("means"), spec("U"),
+                                  block_n=bn)
+
+
+def _best(spec, bn):
+    return gmm_best_pallas.lower(spec("X"), spec("means"), spec("U"),
+                                 block_n=bn)
+
+
+def _stats(spec, bn):
+    return gmm_stats_pallas.lower(spec("X"), spec("logw"), spec("means"),
+                                  spec("U"), nvalid=spec("nvalid"),
+                                  block_n=bn)
+
+
+def _update(spec, bn):
+    return gmm_update_pallas.lower(spec("X"), spec("logw"), spec("means"),
+                                   spec("U"), nvalid=spec("nvalid"),
+                                   block_n=bn)
+
+
+# (rows, D, K, block_n): detection-plane buckets (repro.detect.cache) at the
+# features' widths, up to the largest bucket at the streaming EM's block
+SHAPES = [(256, 3, 3, 256), (4096, 4, 3, 1024), (65536, 4, 5, 4096)]
+
+
+@pytest.mark.parametrize("N,D,K,block_n", SHAPES)
+@pytest.mark.parametrize("lower", [_score, _best, _stats, _update],
+                         ids=["score", "best", "stats", "update"])
+def test_gmm_kernel_compiles_for_v5e(one_chip, lower, N, D, K, block_n):
+    shapes = {"X": ((N, D), jnp.float32), "means": ((K, D), jnp.float32),
+              "U": ((K, D, D), jnp.float32), "logw": ((K,), jnp.float32),
+              "nvalid": ((), jnp.int32)}
+
+    def spec(name):
+        shape, dtype = shapes[name]
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = lower(spec, block_n).compile()
+    assert "tpu_custom_call" in compiled.as_text()
