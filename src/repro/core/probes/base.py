@@ -24,6 +24,9 @@ _NAN = float("nan")
 
 class Probe(abc.ABC):
     name: str = "probe"
+    # seconds of the probe's own work on the monitored job's step thread
+    # (see docs/observability.md for what each probe counts)
+    self_seconds: float = 0.0
 
     def __init__(self):
         self._sink: Optional[Union[EventTable, RingBuffer]] = None

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import json
 import os
+from time import perf_counter
 from typing import Callable, List
 
 import jax
@@ -34,20 +35,25 @@ class JaxRuntimeProbe(Probe):
         # sweep would inject its own compile/dispatch events into the very
         # stream it is scoring (the step thread's synchronous sweeps handle
         # this by detaching the probe; see Session._detection_pause).
+        # self_seconds counts the listeners' time outside detection sweeps
         def on_duration(name: str, secs: float, **kw):
             if in_detection_zone():
                 return
+            t = perf_counter()
             extra = {k: v for k, v in kw.items()
                      if isinstance(v, (int, float, str))}
             self.emit_rows(Layer.XLA, name, self.now(), dur=secs,
                            pid=os.getpid(),
                            meta=json.dumps(extra, separators=(",", ":"))
                            if extra else "")
+            self.self_seconds += perf_counter() - t
 
         def on_event(name: str, **kw):
             if in_detection_zone():
                 return
+            t = perf_counter()
             self.emit_rows(Layer.XLA, name, self.now(), pid=os.getpid())
+            self.self_seconds += perf_counter() - t
 
         self._dur_listener = on_duration
         self._evt_listener = on_event

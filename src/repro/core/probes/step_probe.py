@@ -10,9 +10,11 @@ from __future__ import annotations
 
 import os
 import time
+from time import perf_counter
 from typing import Any, Callable, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.events import Layer
 from repro.core.probes.base import Probe
@@ -47,39 +49,66 @@ class StepProbe(Probe):
         pass
 
     def wrap(self, fn: Callable) -> Callable:
-        """Return a monitored version of `fn` (user code untouched)."""
+        """Return a monitored version of `fn` (user code untouched).
+
+        Spans (`jax.profiler.TraceAnnotation`, entered directly: a helper
+        under ``repro.*`` would itself fire the python probe's hook) mark
+        the call's dispatch (``eacgm.step.call``), the wait for its result
+        (``eacgm.step.wait``) and the probes' work after it
+        (``eacgm.probe.emit``), whose time is charged to each probe's
+        ``self_seconds``."""
 
         def monitored(*args, **kwargs):
             t0 = self.now()
-            out = fn(*args, **kwargs)
-            out = jax.block_until_ready(out)
+            with TraceAnnotation("eacgm.step.call"):
+                out = fn(*args, **kwargs)
+            with TraceAnnotation("eacgm.step.wait"):
+                out = jax.block_until_ready(out)
             exec_dur = self.now() - t0
             if self.extra_latency:  # python-layer fault: real host-side stall
                 time.sleep(self.extra_latency)
-            dur = (self.now() - t0) + self.extra_xla + self.extra_op
-            step = self.step_count
-            self.step_count += 1
-            # runtime/XLA layer: the executable-run duration an eBPF uprobe on
-            # the runtime's execute symbol would time (CUDA-layer analogue)
-            pid = os.getpid()
-            self.emit_rows(Layer.XLA, "executable_run", t0,
-                           dur=exec_dur + self.extra_xla, step=step, pid=pid)
-            self.emit_rows(Layer.STEP, "train_step", t0, dur=dur, step=step,
-                           pid=pid)
-            comm = 0.0
-            if self.collective_probe is not None and self.collective_probe.attached:
-                comm = self.collective_probe.observe_step(step, t0)
-            if self.operator_probe is not None and self.operator_probe.attached:
-                self.operator_probe.observe_step(
-                    step, max(exec_dur - comm, 0.0) + self.extra_op, t0)
-            if self.device_probe is not None:
-                duty = 0.0
-                if dur > 0 and self.flops_per_step:
-                    duty = min(1.0, self.flops_per_step / self.peak_flops / dur)
-                elif dur > 0:
-                    duty = min(1.0, 0.7 + 0.1 * (dur % 0.1))
-                self.device_probe.current_duty = duty
-                self.device_probe.current_mem_gb = self.mem_gb_per_step
+            with TraceAnnotation("eacgm.probe.emit"):
+                t = perf_counter()
+                dur = (self.now() - t0) + self.extra_xla + self.extra_op
+                step = self.step_count
+                self.step_count += 1
+                # runtime/XLA layer: the executable-run duration an eBPF
+                # uprobe on the runtime's execute symbol would time
+                # (CUDA-layer analogue)
+                pid = os.getpid()
+                self.emit_rows(Layer.XLA, "executable_run", t0,
+                               dur=exec_dur + self.extra_xla, step=step,
+                               pid=pid)
+                self.emit_rows(Layer.STEP, "train_step", t0, dur=dur,
+                               step=step, pid=pid)
+                now = perf_counter()
+                self.self_seconds += now - t
+                t = now
+                comm = 0.0
+                probe = self.collective_probe
+                if probe is not None and probe.attached:
+                    comm = probe.observe_step(step, t0)
+                    now = perf_counter()
+                    probe.self_seconds += now - t
+                    t = now
+                probe = self.operator_probe
+                if probe is not None and probe.attached:
+                    probe.observe_step(
+                        step, max(exec_dur - comm, 0.0) + self.extra_op, t0)
+                    now = perf_counter()
+                    probe.self_seconds += now - t
+                    t = now
+                probe = self.device_probe
+                if probe is not None:
+                    duty = 0.0
+                    if dur > 0 and self.flops_per_step:
+                        duty = min(1.0, self.flops_per_step
+                                   / self.peak_flops / dur)
+                    elif dur > 0:
+                        duty = min(1.0, 0.7 + 0.1 * (dur % 0.1))
+                    probe.current_duty = duty
+                    probe.current_mem_gb = self.mem_gb_per_step
+                    probe.self_seconds += perf_counter() - t
             return out
 
         monitored.__wrapped__ = fn

@@ -24,7 +24,11 @@ Design points:
   ``error`` set; the worker never dies. Callers decide whether to re-raise.
 
 Worker tasks run inside ``guard.detection_zone()`` so the globally-registered
-XLA monitoring listeners drop events the sweep itself generates.
+XLA monitoring listeners drop events the sweep itself generates, and inside
+an ``eacgm.detect.sweep`` profiler span (`jax.profiler.TraceAnnotation`).
+Beside the wall time spent in sweeps (``busy_seconds``) the executor counts
+the time started sweeps waited in the queue (``wait_seconds``, summed over
+``started``); a coalesced task never starts and adds no wait.
 """
 from __future__ import annotations
 
@@ -32,6 +36,8 @@ import dataclasses
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.detect.guard import detection_zone
 
@@ -89,10 +95,12 @@ class DetectionExecutor:
         self._closed = False
         # counters (read under lock)
         self._submitted = 0
+        self._started = 0
         self._completed = 0
         self._coalesced = 0
         self._errors = 0
         self._busy_seconds = 0.0
+        self._wait_seconds = 0.0
         self._worker: Optional[threading.Thread] = None
         if mode == "thread":
             self._worker = threading.Thread(target=self._run, name=name,
@@ -156,21 +164,26 @@ class DetectionExecutor:
             return {
                 "mode": self.mode,
                 "submitted": self._submitted,
+                "started": self._started,
                 "completed": self._completed,
                 "coalesced": self._coalesced,
                 "errors": self._errors,
                 "queue_depth": len(self._queue)
                 + (1 if self._active_key is not None else 0),
                 "busy_seconds": self._busy_seconds,
+                "wait_seconds": self._wait_seconds,
             }
 
     # -- worker -----------------------------------------------------------
 
     def _execute(self, task: _Task) -> None:
         started = time.monotonic()
+        with self._lock:
+            self._started += 1
+            self._wait_seconds += started - task.submitted_ts
         value, error = None, None
         try:
-            with detection_zone():
+            with detection_zone(), TraceAnnotation("eacgm.detect.sweep"):
                 value = task.fn()
         except BaseException as exc:  # noqa: BLE001 — errors are data here
             error = exc

@@ -55,7 +55,6 @@ class FleetSweepOutcome:
     # (layer, node_id, floor_ts) triples, applied at admit
     floors: List[Tuple[Layer, int, float]]
     t_latest: float
-    detect_s: float
 
 
 class _MergedWindow:
@@ -237,7 +236,6 @@ class HierarchicalMonitor:
         self.ticks = 0
         self.detect_seconds = 0.0
         self.merge_seconds = 0.0  # fleet-tier incident merge wall time
-        self.last_detect_ms = 0.0
         self.last_detections: Dict[Layer, WindowDetection] = {}
         self.wire_tap: Optional[Callable[[bytes], None]] = None
 
@@ -316,9 +314,7 @@ class HierarchicalMonitor:
         closed = self.engine.finalise(t_max)
         self.merge_seconds += time.perf_counter() - t1
         self.last_detections = merge_detections(per_group)
-        dt = time.perf_counter() - t0
-        self.detect_seconds += dt
-        self.last_detect_ms = 1e3 * dt
+        self.detect_seconds += time.perf_counter() - t0
         self.ticks += 1
         return closed
 
@@ -339,7 +335,6 @@ class HierarchicalMonitor:
         """Worker half: per-group late-warmup + detect against frozen
         snapshots. Mutates only the group detectors (serialised by the
         executor); the shared incident engine is untouched until admit."""
-        t0 = time.perf_counter()
         per_group: Dict[int, Dict[Layer, WindowDetection]] = {}
         floors: List[Tuple[Layer, int, float]] = []
         t_latest = 0.0
@@ -354,12 +349,13 @@ class HierarchicalMonitor:
                 g.detect_seconds += time.perf_counter() - t1
             t_latest = max(t_latest, snap.t_latest)
         return FleetSweepOutcome(per_group=per_group, floors=floors,
-                                 t_latest=t_latest,
-                                 detect_s=time.perf_counter() - t0)
+                                 t_latest=t_latest)
 
-    def admit(self, outcome: FleetSweepOutcome) -> List[Incident]:
+    def admit(self, outcome: FleetSweepOutcome,
+              detect_s: float = 0.0) -> List[Incident]:
         """Step-thread half two: publish a sweep — floors, fleet-tier
-        incident merge, tick accounting."""
+        incident merge, tick accounting. ``detect_s`` is the sweep's wall
+        time as the executor timed it (`SweepResult.wall_s`)."""
         for layer, nid, ts in outcome.floors:
             self.engine.set_node_floor(layer, nid, ts)
         t1 = time.perf_counter()
@@ -370,8 +366,7 @@ class HierarchicalMonitor:
         merge_dt = time.perf_counter() - t1
         self.merge_seconds += merge_dt
         self.last_detections = merge_detections(outcome.per_group)
-        self.detect_seconds += outcome.detect_s + merge_dt
-        self.last_detect_ms = 1e3 * (outcome.detect_s + merge_dt)
+        self.detect_seconds += detect_s + merge_dt
         self.ticks += 1
         return closed
 
@@ -442,7 +437,6 @@ class HierarchicalMonitor:
             "ticks": self.ticks,
             "detect_ms_per_tick":
                 1e3 * self.detect_seconds / max(self.ticks, 1),
-            "last_detect_ms": self.last_detect_ms,
             "incidents": len(self.engine.incidents),
             # tier wall-times: the honest critical path of a deployment
             # where each group aggregates on its own host

@@ -122,6 +122,7 @@ def gmm_score_pallas(X, means, prec_chol, *, block_n: int = 1024,
         X, means, prec_chol, block_n)
     out = pl.pallas_call(
         _score_kernel,
+        name="gmm_score",
         grid=(n_blocks,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_n, K), lambda i: (i, 0)),
@@ -139,6 +140,7 @@ def gmm_best_pallas(X, means, prec_chol, *, block_n: int = 1024,
         X, means, prec_chol, block_n)
     best, arg = pl.pallas_call(
         _best_kernel,
+        name="gmm_best",
         grid=(n_blocks,),
         in_specs=in_specs,
         # (n_blocks, 1, block_n): each block writes one lane-dense row. A 1-D

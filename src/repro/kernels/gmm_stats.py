@@ -126,12 +126,13 @@ def _prepare(X, means, prec_chol, nvalid, block_n):
     return X, mu_u, logdet, nvalid, n_blocks
 
 
-def _launch(kernel, X, log_weights, mu_u, prec_chol, logdet, nvalid,
+def _launch(kernel, name, X, log_weights, mu_u, prec_chol, logdet, nvalid,
             n_blocks, block_n, interpret):
     K, D = mu_u.shape
     full = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
     nk, sx, sxx, ll = pl.pallas_call(
         kernel,
+        name=name,
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((block_n, D), lambda i: (i, 0)),
@@ -160,8 +161,8 @@ def gmm_stats_pallas(X, log_weights, means, prec_chol, *, nvalid=None,
     zero-padded X with the true row count to reuse one compiled shape."""
     X, mu_u, logdet, nvalid, n_blocks = _prepare(X, means, prec_chol,
                                                  nvalid, block_n)
-    return _launch(_stats_kernel, X, log_weights, mu_u, prec_chol, logdet,
-                   nvalid, n_blocks, block_n, interpret)
+    return _launch(_stats_kernel, "gmm_stats", X, log_weights, mu_u,
+                   prec_chol, logdet, nvalid, n_blocks, block_n, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -173,5 +174,5 @@ def gmm_update_pallas(X, log_weights, means, prec_chol, *, nvalid=None,
     O(K D^2) host work against one kernel launch."""
     X, mu_u, logdet, nvalid, n_blocks = _prepare(X, means, prec_chol,
                                                  nvalid, block_n)
-    return _launch(_update_kernel, X, log_weights, mu_u, prec_chol, logdet,
-                   nvalid, n_blocks, block_n, interpret)
+    return _launch(_update_kernel, "gmm_update", X, log_weights, mu_u,
+                   prec_chol, logdet, nvalid, n_blocks, block_n, interpret)

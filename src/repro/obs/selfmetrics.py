@@ -43,6 +43,7 @@ METRIC_NAMES = (
     "eacgm_ring_occupancy",
     "eacgm_ring_capacity",
     "eacgm_probe_events_emitted_total",
+    "eacgm_probe_self_seconds_total",
     # per-node agent (wire transport + backpressure governor)
     "eacgm_agent_flushes_total",
     "eacgm_agent_events_shipped_total",
@@ -83,6 +84,7 @@ METRIC_NAMES = (
     "eacgm_detect_sweep_errors_total",
     "eacgm_detect_queue_depth",
     "eacgm_detect_busy_seconds_total",
+    "eacgm_detect_wait_seconds_total",
     "eacgm_detect_lag_seconds",
     "eacgm_detect_lag_steps",
     "eacgm_detect_compile_cache_hits_total",
@@ -156,6 +158,11 @@ class SessionObs:
         self.probe_emitted = r.counter(
             "eacgm_probe_events_emitted_total",
             "Events emitted per probe (lifetime)",
+            labels=("node", "probe"))
+        self.probe_self_s = r.counter(
+            "eacgm_probe_self_seconds_total",
+            "Seconds of the probe's own work on the monitored step thread "
+            "(the python probe's is a sampled estimate)",
             labels=("node", "probe"))
         self.agent_flushes = r.counter(
             "eacgm_agent_flushes_total",
@@ -274,6 +281,10 @@ class SessionObs:
         self.detect_busy_s = r.counter(
             "eacgm_detect_busy_seconds_total",
             "Cumulative wall time the executor worker spent inside sweeps")
+        self.detect_wait_s = r.counter(
+            "eacgm_detect_wait_seconds_total",
+            "Cumulative time started sweeps waited in the executor queue "
+            "(coalesced sweeps never start and add none)")
         self.detect_lag_s = r.gauge(
             "eacgm_detect_lag_seconds",
             "Submit-to-finish latency of the most recently admitted sweep "
@@ -358,6 +369,8 @@ class SessionObs:
             for p in handle.collector.probes:
                 self.probe_emitted.set_total(p.emitted, node=node,
                                              probe=p.name)
+                self.probe_self_s.set_total(p.self_seconds, node=node,
+                                            probe=p.name)
         backend = s._backend
         if s.spec.mode == "stream" and backend is not None:
             self._collect_stream(backend.monitor)
@@ -374,6 +387,7 @@ class SessionObs:
             self.sweep_errors.set_total(st["errors"])
             self.detect_queue_depth.set(st["queue_depth"])
             self.detect_busy_s.set_total(st["busy_seconds"])
+            self.detect_wait_s.set_total(st["wait_seconds"])
             self.detect_lag_s.set(s.async_lag_seconds)
             self.detect_lag_steps.set(s.async_lag_steps)
         from repro.detect import SHAPE_CACHE

@@ -26,6 +26,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Protocol, runtime_checkable
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.collector import Collector
 from repro.core.detector import DetectionResult, FullStackMonitor
 from repro.core.events import Event, Layer
@@ -211,12 +213,14 @@ class OnlineGMMBackend:
         cadence point's snapshot (staleness in ``lag_steps``/
         ``lag_seconds``). With an inline executor this is byte-identical to
         ``update()``."""
-        snap = self.monitor.snapshot()
+        with TraceAnnotation("eacgm.session.snapshot"):
+            snap = self.monitor.snapshot()
         if snap is None:
             return self.monitor.last_detections
         self._executor.submit(
             "stream", lambda: self.monitor.detect_snapshot(snap), step=step)
-        self._admit_completed(step)
+        with TraceAnnotation("eacgm.session.admit"):
+            self._admit_completed(step)
         return self.monitor.last_detections
 
     def _admit_completed(self, step: int) -> None:
@@ -225,7 +229,7 @@ class OnlineGMMBackend:
                 continue
             if r.error is not None:
                 raise r.error
-            self.closed.extend(self.monitor.admit(r.value))
+            self.closed.extend(self.monitor.admit(r.value, r.wall_s))
             self.lag_steps = step - r.step
             self.lag_seconds = r.lag_s
             self.sweeps_admitted += 1

@@ -27,6 +27,7 @@ import warnings
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.collector import Collector
 from repro.core.events import (Event, Layer, concat_columns,
@@ -233,6 +234,22 @@ class Session:
             out[a.kind] = out.get(a.kind, 0) + 1
         return out
 
+    def self_stats(self) -> Dict[str, Any]:
+        """The monitor's own cost so far, as cumulative totals:
+        ``probes[node][probe]`` is each probe's ``self_seconds`` on the step
+        thread, and ``detect`` holds the detection executor's ``started``
+        and ``completed`` sweeps with their queue ``wait_seconds`` and run
+        ``busy_seconds`` (empty without an async executor)."""
+        detect: Dict[str, float] = {}
+        if self._executor is not None:
+            st = self._executor.stats()
+            detect = {k: st[k] for k in ("started", "completed",
+                                         "wait_seconds", "busy_seconds")}
+        return {"probes": {nid: {p.name: p.self_seconds
+                                 for p in h.collector.probes}
+                           for nid, h in self._nodes.items()},
+                "detect": detect}
+
     # -- fleet membership -----------------------------------------------------
     def node(self, node_id: int = 0, ts_offset: float = 0.0) -> NodeHandle:
         if self.off:
@@ -304,28 +321,33 @@ class Session:
         """Call once per training/serving step; the spec decides when this
         flushes, fits, detects, and forms incidents. The SLO plane (when
         configured) is judged every call — breaches must not wait for a
-        detector cadence point."""
-        out = StepOutcome()
-        if self.off or step <= 0:
+        detector cadence point. Runs inside the ``eacgm.session.on_step``
+        profiler span; its children are ``eacgm.session.snapshot`` (poll +
+        freeze), ``eacgm.session.admit`` (drain, incident engine,
+        diagnoses) and ``eacgm.session.sinks``."""
+        with TraceAnnotation("eacgm.session.on_step"):
+            out = StepOutcome()
+            if self.off or step <= 0:
+                return out
+            self._last_step = max(self._last_step, step)
+            det = self.spec.detector
+            cadence = step % (det.flush_every if self.spec.mode == "stream"
+                              else det.sweep_every) == 0
+            if cadence:
+                self._detect_step(step, out)
+            self._slo_step(out)
+            if not cadence and not out:
+                return out
+            if self.governor is not None and out.detections:
+                out.actions = self.governor.decide(out.detections)
+            if self.governor is not None and out.diagnoses:
+                out.actions.extend(d.action for d in out.diagnoses)
+                out.actions.sort(key=lambda a: -a.severity)
+            self._diagnoses_seen.extend(out.diagnoses)
+            self._actions_seen.extend(out.actions)
+            with TraceAnnotation("eacgm.session.sinks"):
+                self._refresh_sinks()
             return out
-        self._last_step = max(self._last_step, step)
-        det = self.spec.detector
-        cadence = step % (det.flush_every if self.spec.mode == "stream"
-                          else det.sweep_every) == 0
-        if cadence:
-            self._detect_step(step, out)
-        self._slo_step(out)
-        if not cadence and not out:
-            return out
-        if self.governor is not None and out.detections:
-            out.actions = self.governor.decide(out.detections)
-        if self.governor is not None and out.diagnoses:
-            out.actions.extend(d.action for d in out.diagnoses)
-            out.actions.sort(key=lambda a: -a.severity)
-        self._diagnoses_seen.extend(out.diagnoses)
-        self._actions_seen.extend(out.actions)
-        self._refresh_sinks()
-        return out
 
     def _detect_step(self, step: int, out: StepOutcome) -> None:
         """One detector cadence point (anomaly plane), filling ``out``."""
@@ -345,12 +367,14 @@ class Session:
                     out.detections = self._backend.update()
             out.incidents = self._backend.closed[n_closed:]
             if out.incidents and self._diagnoser is not None:
-                out.diagnoses = self._diagnoser.diagnose_all(
-                    out.incidents, self._stream_evidence())
+                with TraceAnnotation("eacgm.session.admit"):
+                    out.diagnoses = self._diagnoser.diagnose_all(
+                        out.incidents, self._stream_evidence())
         else:  # batch: periodic snapshot sweep (fit on the clean prefix)
-            cols = self._snapshot_columns()
-            train = select_columns(
-                cols, cols["step"] < step - det.holdoff_steps)
+            with TraceAnnotation("eacgm.session.snapshot"):
+                cols = self._snapshot_columns()
+                train = select_columns(
+                    cols, cols["step"] < step - det.holdoff_steps)
             if not train["ts"].shape[0]:
                 return
             with self._detection_pause():
@@ -395,7 +419,8 @@ class Session:
             return backend.update(cols)
 
         self._executor.submit("batch", sweep, step=step)
-        return self._admit_batch(step)
+        with TraceAnnotation("eacgm.session.admit"):
+            return self._admit_batch(step)
 
     def _admit_batch(self, step: int) -> Dict[Layer, Any]:
         detections: Dict[Layer, Any] = {}

@@ -57,7 +57,6 @@ class SweepOutcome:
     detections: Dict[Layer, WindowDetection]
     fitted: List[Layer]  # layers late-warmup fitted during this sweep
     t_latest: float  # snapshot fleet clock (floors + incident `now`)
-    detect_s: float  # sweep wall time (compute only, excludes queueing)
 
 
 def export_windows_trace(windows, path: str) -> str:
@@ -104,7 +103,6 @@ class StreamMonitor:
         self.agents: Dict[int, NodeAgent] = {}
         self.ticks = 0
         self.detect_seconds = 0.0  # cumulative detection wall time
-        self.last_detect_ms = 0.0  # wall time of the most recent tick
         self.last_detections: Dict[Layer, WindowDetection] = {}
         # optional observer of every wire batch as it leaves an agent — the
         # session sink pipeline tees the transport through this
@@ -152,9 +150,7 @@ class StreamMonitor:
         self.last_detections = self.detector.detect(self.aggregator)
         closed = self.engine.update(self.last_detections,
                                     now=self.aggregator.t_latest)
-        dt = time.perf_counter() - t0
-        self.detect_seconds += dt
-        self.last_detect_ms = 1e3 * dt
+        self.detect_seconds += time.perf_counter() - t0
         self.ticks += 1
         return closed
 
@@ -174,22 +170,21 @@ class StreamMonitor:
         """Worker half: late-warmup + detect against a frozen snapshot.
         Touches only detector state — safe off-thread because the executor
         serialises sweeps per key."""
-        t0 = time.perf_counter()
         fitted = self.detector.warmup(snap)
         detections = self.detector.detect(snap)
         return SweepOutcome(detections=detections, fitted=fitted,
-                            t_latest=snap.t_latest,
-                            detect_s=time.perf_counter() - t0)
+                            t_latest=snap.t_latest)
 
-    def admit(self, outcome: SweepOutcome) -> List[Incident]:
+    def admit(self, outcome: SweepOutcome,
+              detect_s: float = 0.0) -> List[Incident]:
         """Step-thread half two: publish a sweep's results — late-warmup
-        floors, incident engine update, tick accounting."""
+        floors, incident engine update, tick accounting. ``detect_s`` is the
+        sweep's wall time as the executor timed it (`SweepResult.wall_s`)."""
         for layer in outcome.fitted:
             self.engine.set_layer_floor(layer, outcome.t_latest)
         self.last_detections = outcome.detections
         closed = self.engine.update(outcome.detections, now=outcome.t_latest)
-        self.detect_seconds += outcome.detect_s
-        self.last_detect_ms = 1e3 * outcome.detect_s
+        self.detect_seconds += detect_s
         self.ticks += 1
         return closed
 
@@ -230,7 +225,6 @@ class StreamMonitor:
             "ticks": self.ticks,
             "detect_ms_per_tick":
                 1e3 * self.detect_seconds / max(self.ticks, 1),
-            "last_detect_ms": self.last_detect_ms,
             "incidents": len(self.engine.incidents),
             # monitor-side collection loss, aggregated across the fleet:
             # ring overwrites at the source + names clipped at the ring or

@@ -16,6 +16,10 @@ batch `FullStackMonitor`:
 * a likelihood collapse on the *inlier* rows (beyond ``drift_tol`` nats)
   signals concept drift and triggers a full cold refit + threshold
   recalibration.
+
+Each sweep's phases are profiler spans (`jax.profiler.TraceAnnotation`):
+``eacgm.detect.featurize``, ``eacgm.detect.score`` and ``eacgm.detect.fit``
+(EM: cold fits, warm refits, incremental folds).
 """
 from __future__ import annotations
 
@@ -24,6 +28,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.events import Layer
 from repro.core.features import (COLLECTIVE_FEATURES, DEVICE_FEATURES,
@@ -266,10 +271,12 @@ class OnlineGMMDetector:
             window = agg.window(layer)
             if len(window) < self.min_events:
                 continue
-            fs = _raw_features(layer, window.view())
+            with TraceAnnotation("eacgm.detect.featurize"):
+                fs = _raw_features(layer, window.view())
             if fs is None or fs.X.shape[0] < self.min_events:
                 continue
-            self.states[layer] = self._cold_fit(layer, fs)
+            with TraceAnnotation("eacgm.detect.fit"):
+                self.states[layer] = self._cold_fit(layer, fs)
             fitted.append(layer)
         return fitted
 
@@ -284,15 +291,19 @@ class OnlineGMMDetector:
         the model: warm EM refit on the inlier rows, cold refit on drift."""
         out: Dict[Layer, WindowDetection] = {}
         for layer, state in self.states.items():
-            fs = self._featurize(agg.window(layer), state)
-            if fs is None or not len(fs.X):
-                continue
-            Xs = ((fs.X - state.mean) / state.std).astype(np.float32)
-            scores = self._score_bucketed(Xs, state.params)
-            flags = scores < state.log_delta
+            with TraceAnnotation("eacgm.detect.featurize"):
+                fs = self._featurize(agg.window(layer), state)
+                if fs is None or not len(fs.X):
+                    continue
+                Xs = ((fs.X - state.mean) / state.std).astype(np.float32)
+            with TraceAnnotation("eacgm.detect.score"):
+                scores = self._score_bucketed(Xs, state.params)
+                flags = scores < state.log_delta
             mode = "none"
             if refit and self.track:
-                mode = self._track(layer, state, Xs, flags, scores, fs.ts)
+                with TraceAnnotation("eacgm.detect.fit"):
+                    mode = self._track(layer, state, Xs, flags, scores,
+                                       fs.ts)
             out[layer] = WindowDetection(
                 layer=layer, flags=flags, scores=scores,
                 log_delta=state.log_delta, steps=fs.steps, nodes=fs.nodes,

@@ -92,6 +92,47 @@ def test_executor_thread_coalesces_queued_tasks():
     ex.close()
 
 
+def test_executor_wait_counts_started_tasks_only():
+    """``wait_seconds`` sums submit-to-start over the tasks that started:
+    a task coalesced away while queued never starts and adds nothing."""
+    ex = DetectionExecutor(mode="thread")
+    started = threading.Event()
+    release = threading.Event()
+
+    def blocker():
+        started.set()
+        assert release.wait(30)
+
+    ex.submit("a", blocker)
+    assert started.wait(30)
+    ex.submit("b", lambda: "b1")  # coalesced away by b2
+    time.sleep(0.2)
+    ex.submit("b", lambda: "b2")
+    time.sleep(0.1)
+    release.set()
+    assert ex.flush(timeout=30)
+    results = ex.drain()
+    s = ex.stats()
+    assert s["started"] == s["completed"] == 2 and s["coalesced"] == 1
+    waits = [r.started_ts - r.submitted_ts for r in results]
+    assert s["wait_seconds"] == pytest.approx(sum(waits))
+    # b2 waited behind the blocker for >= 0.1 s; b1's 0.3 s counts nowhere
+    assert 0.1 <= waits[1] < 0.3 and s["wait_seconds"] < 0.3
+    assert s["busy_seconds"] >= 0.3
+    ex.close()
+
+
+def test_executor_inline_never_waits():
+    ex = DetectionExecutor(mode="inline")
+    for _ in range(3):
+        ex.submit("k", lambda: time.sleep(0.01))
+    s = ex.stats()
+    assert s["started"] == s["completed"] == s["submitted"] == 3
+    assert s["coalesced"] == 0
+    assert 0.0 <= s["wait_seconds"] < 0.01 <= s["busy_seconds"] / 3
+    ex.close()
+
+
 def test_executor_error_is_data_and_worker_survives():
     ex = DetectionExecutor(mode="thread")
 

@@ -4,6 +4,8 @@ endpoint, and fleet freshness (a node that stops flushing flips to stale)."""
 import json
 import urllib.request
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -249,6 +251,43 @@ def test_endpoint_serves_valid_exposition_and_health(tmp_path):
     with open(report.sink_outputs["prometheus"]) as f:
         parse_exposition(f.read())
     assert "board" in report.sink_outputs
+
+
+def test_self_time_counters_mirror_session_self_stats(tmp_path):
+    """``Session.self_stats()`` holds each probe's self time and the
+    executor's wait/run totals; the exposition mirrors them."""
+    spec = _stream_spec(tmp_path)
+    spec.probes = ["operator", "collective", "device", "step"]
+    spec.detector.executor = "inline"
+    session = Session(spec)
+
+    @jax.jit
+    def step(x):
+        return x + 1.0
+
+    with session.monitoring():
+        fn = session.observe_step_fn(step)
+        _emit_steps(session.node(0).collector.buffer, range(40))
+        session.warmup()
+        x = jnp.zeros(4)
+        for s in range(1, 21):
+            x = fn(x)
+            session.on_step(s)
+        stats = session.self_stats()
+        exp = parse_exposition(session.obs_layer().registry.render())
+    probes = stats["probes"][0]
+    assert set(probes) == {"operator", "collective", "device", "step"}
+    assert probes["step"] > 0.0
+    for name, sec in probes.items():
+        assert exp.sample("eacgm_probe_self_seconds_total", node="0",
+                          probe=name).value == pytest.approx(sec)
+    detect = stats["detect"]
+    assert detect["started"] == detect["completed"] >= 1
+    assert detect["busy_seconds"] > 0.0 and detect["wait_seconds"] >= 0.0
+    assert exp.sample("eacgm_detect_wait_seconds_total").value == \
+        pytest.approx(detect["wait_seconds"])
+    assert Session(MonitorSpec(mode="off")).self_stats() == {
+        "probes": {}, "detect": {}}
 
 
 def test_stale_node_flips_when_agent_stops_flushing(tmp_path):

@@ -59,6 +59,46 @@ def test_python_probe_records_repro_calls():
     assert any("Standardizer" in n or "features" in n for n in names)
 
 
+def test_python_probe_self_time_is_scaled_from_timed_events():
+    """One hook event in TIME_EVERY is timed; the estimate scales their
+    mean by the exact count of hook events."""
+    from repro.core.probes.python_probe import TIME_EVERY
+    from repro.core.features import Standardizer
+
+    p = PythonProbe(include=("repro",))
+    assert p.self_seconds == 0.0
+    p.attach(RingBuffer(100000))
+    for _ in range(50):
+        Standardizer().fit(np.ones((10, 2)))
+    p.detach()
+    assert p.hook_events >= 2 * TIME_EVERY
+    assert p._timed_events == p.hook_events // TIME_EVERY
+    assert p.self_seconds == pytest.approx(
+        p._timed_seconds / p._timed_events * p.hook_events)
+    assert p.self_seconds > 0.0
+
+
+def test_step_probe_charges_each_probes_self_time():
+    """The step probe times its own emission and each dependent probe's
+    per-step hook; the other probes' counters start at nought."""
+    col = Collector.standard(with_python=False, device_interval=10.0)
+
+    @jax.jit
+    def step(x):
+        return x * 2.0
+
+    with col.monitoring():
+        fn = col.observe_step_fn(step, sample_args=(jnp.ones((8, 8)),))
+        x = jnp.ones((8, 8))
+        for _ in range(5):
+            x = fn(x)
+    own = {p.name: p.self_seconds for p in col.probes}
+    assert set(own) == {"xla", "operator", "collective", "device", "step"}
+    for name in ("operator", "collective", "device", "step"):
+        assert own[name] > 0.0, name
+    assert own["xla"] >= 0.0
+
+
 def test_hlo_collective_parsing_sharded_module():
     """Compile a genuinely sharded module in a subprocess (needs >1 device)."""
     import subprocess

@@ -67,6 +67,18 @@ def _update(spec, bn):
                                    block_n=bn)
 
 
+def _shapes(one_chip, N, D, K):
+    shapes = {"X": ((N, D), jnp.float32), "means": ((K, D), jnp.float32),
+              "U": ((K, D, D), jnp.float32), "logw": ((K,), jnp.float32),
+              "nvalid": ((), jnp.int32)}
+
+    def spec(name):
+        shape, dtype = shapes[name]
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return spec
+
+
 # (rows, D, K, block_n): detection-plane buckets (repro.detect.cache) at the
 # features' widths, up to the largest bucket at the streaming EM's block
 SHAPES = [(256, 3, 3, 256), (4096, 4, 3, 1024), (65536, 4, 5, 4096)]
@@ -76,13 +88,19 @@ SHAPES = [(256, 3, 3, 256), (4096, 4, 3, 1024), (65536, 4, 5, 4096)]
 @pytest.mark.parametrize("lower", [_score, _best, _stats, _update],
                          ids=["score", "best", "stats", "update"])
 def test_gmm_kernel_compiles_for_v5e(one_chip, lower, N, D, K, block_n):
-    shapes = {"X": ((N, D), jnp.float32), "means": ((K, D), jnp.float32),
-              "U": ((K, D, D), jnp.float32), "logw": ((K,), jnp.float32),
-              "nvalid": ((), jnp.int32)}
-
-    def spec(name):
-        shape, dtype = shapes[name]
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    compiled = lower(spec, block_n).compile()
+    compiled = lower(_shapes(one_chip, N, D, K), block_n).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("lower,name", [
+    (_score, "gmm_score"), (_best, "gmm_best"), (_stats, "gmm_stats"),
+    (_update, "gmm_update")], ids=["score", "best", "stats", "update"])
+def test_gmm_kernel_carries_its_name(one_chip, lower, name):
+    """Each kernel's custom call is named after its ``pallas_call``
+    ``name=``, which is what the device trace's reduction matches
+    (``benchmarks/onchip/program_trace.py``): not after the jitted
+    wrapper that holds it."""
+    text = lower(_shapes(one_chip, 256, 3, 3), 256).compile().as_text()
+    calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(c.startswith(f"%{name}.") for c in calls), calls
