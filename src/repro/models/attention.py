@@ -1,12 +1,19 @@
 """Attention: GQA / sliding-window / MLA, full-sequence (blocked, online-softmax)
 and single-token decode with KV caches (full, rolling-buffer, MLA-latent).
 
-The full-sequence path scans over KV blocks with an online softmax so the
-S x S score matrix is never materialised — O(S * block) memory, which is what
-makes the 32k prefill dry-run cells feasible and keeps the HBM roofline honest.
+The full-sequence path has two implementations of one algorithm. On a TPU,
+a call that the kernel can take (MHA, Dk == Dv, no window, no query offset,
+Sq == Sk a multiple of 128, no mesh) runs the Pallas flash kernel
+(``splash_attention``): causal blocks above the diagonal are skipped and no
+score block leaves VMEM. Everything else, and every run off a TPU, scans over
+KV blocks with an online softmax (``_flash``): O(S * kv_block) memory, which
+makes the 32k prefill dry-run cells feasible. That scan writes the whole
+S x S score matrix (and p, dp, ds at the same size) once kv_block >= S.
+``PATH_COUNTS`` counts the calls traced on each path.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
@@ -14,11 +21,16 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro.config import ModelConfig
 from repro.models.layers import apply_norm, apply_rope, fanin_init
 
 NEG_INF = -1e30
+
+# attention calls traced per path ("pallas" / "blocked"): counted once per
+# trace, not per execution
+PATH_COUNTS: collections.Counter = collections.Counter()
 
 
 # ---------------------------------------------------------------------------
@@ -181,6 +193,33 @@ def _flash_vjp_bwd(causal, window, q_offset, kv_block, scale, scores_bf16,
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _takes_kernel(q, v, *, window, q_offset, mesh) -> bool:
+    """Whether the Pallas flash kernel can take this call."""
+    _, Sq, H, Dk = q.shape
+    _, Sk, KV, Dv = v.shape
+    return (jax.default_backend() == "tpu" and window == 0 and q_offset == 0
+            and Sq == Sk and H == KV and Dk == Dv and Sq % 128 == 0
+            and mesh is None)
+
+
+def _splash(q, k, v, causal, scale, interpret=False):
+    """(B, S, H, D) MHA through ``splash_attention``: bf16 operands, f32
+    softmax statistics and accumulators. The largest of 512/256/128 that
+    divides S sets every block, and one fused kernel gives dq, dk and dv:
+    the fastest of a sweep on a TPU v5e at GPT-2's train shape (PERF.md)."""
+    _, S, H, _ = q.shape
+    blk = next(b for b in (512, 256, 128) if S % b == 0)
+    head_mask = splash.CausalMask((S, S)) if causal else splash.FullMask((S, S))
+    kernel = splash.make_splash_mha_single_device(
+        splash.MultiHeadMask([head_mask] * H),
+        block_sizes=splash.BlockSizes(
+            block_q=blk, block_kv=blk, block_q_dkv=blk, block_kv_dkv=blk,
+            use_fused_bwd_kernel=True),
+        interpret=interpret)
+    heads = lambda x: x.transpose(0, 2, 1, 3)  # (B,S,H,D) <-> (B,H,S,D)
+    return heads(jax.vmap(kernel)(heads(q * scale), heads(k), heads(v)))
+
+
 def blocked_attention(
     q: jnp.ndarray,  # (B, Sq, H, hd_qk)
     k: jnp.ndarray,  # (B, Sk, KV, hd_qk)
@@ -192,11 +231,17 @@ def blocked_attention(
     kv_block: int = 1024,
     scale: Optional[float] = None,
     scores_bf16: bool = False,
+    mesh=None,
 ) -> jnp.ndarray:
-    """Flash-style blocked attention: online-softmax forward, block-recompute
-    custom VJP — O(S * block) memory in BOTH directions."""
+    """Flash-style attention: the Pallas kernel where it can take the call
+    (``_takes_kernel``), else the online-softmax scan with its block-recompute
+    custom VJP. ``mesh``: the call's mesh, if it is sharded."""
     Dk = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(Dk)
+    if _takes_kernel(q, v, window=window, q_offset=q_offset, mesh=mesh):
+        PATH_COUNTS["pallas"] += 1
+        return _splash(q, k, v, causal, scale)
+    PATH_COUNTS["blocked"] += 1
     return _flash(q, k, v, causal, window, q_offset, kv_block, float(scale),
                   scores_bf16)
 
@@ -223,7 +268,8 @@ def gqa_forward(params, cfg: ModelConfig, x, *, kv_block: int = 1024, rt=None):
         q = rt.shard(q, seq_shard)
     o = blocked_attention(q, k, v, causal=cfg.causal,
                           window=cfg.sliding_window, kv_block=kv_block,
-                          scores_bf16=bool(rt and rt.attn_scores_bf16))
+                          scores_bf16=bool(rt and rt.attn_scores_bf16),
+                          mesh=rt and rt.mesh)
     if seq_shard is not None:
         o = rt.shard(o, seq_shard)
     return o.reshape(B, S, cfg.n_heads * hd) @ params["out"]["kernel"].astype(x.dtype)
@@ -268,7 +314,8 @@ def mla_forward(params, cfg: ModelConfig, x, *, kv_block: int = 1024, rt=None):
 
     o = blocked_attention(q_full, k, v, causal=cfg.causal, kv_block=kv_block,
                           scale=1.0 / math.sqrt(dn + dr),
-                          scores_bf16=bool(rt and rt.attn_scores_bf16))
+                          scores_bf16=bool(rt and rt.attn_scores_bf16),
+                          mesh=rt and rt.mesh)
     return o.reshape(B, S, H * dv) @ params["out"]["kernel"].astype(x.dtype)
 
 

@@ -158,3 +158,78 @@ def test_flash_vjp_matches_naive_attention_grads():
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-4)
+
+
+def _mha_inputs(B=1, S=256, H=2, D=64, dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return tuple(jax.random.normal(k, (B, S, H, D), dtype) for k in ks)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_pallas_attention_matches_naive_and_flash(causal):
+    """The Pallas flash kernel (interpret mode; blocks of 128 at S 256) gives
+    the output and q/k/v gradients of plain softmax attention and of the
+    online-softmax scan that it replaces on the chip."""
+    from repro.models.attention import _flash, _splash
+
+    q, k, v = _mha_inputs()
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    paths = {
+        "pallas": lambda q, k, v: _splash(q, k, v, causal, scale,
+                                          interpret=True),
+        "naive": lambda q, k, v: jax.nn.dot_product_attention(
+            q, k, v, is_causal=causal),
+        "flash": lambda q, k, v: _flash(q, k, v, causal, 0, 0, 128, scale,
+                                        False),
+    }
+    outs = {}
+    for name, f in paths.items():
+        loss = lambda *a: jnp.sum(jnp.sin(f(*a)))
+        outs[name] = (f(q, k, v),
+                      *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+    for ref in ("naive", "flash"):
+        for a, b in zip(outs["pallas"], outs[ref]):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-4)
+
+
+# (B, Sq, H, Dk), (B, Sk, KV, Dv), window, q_offset, mesh, backend -> path
+_DISPATCH = {
+    "mha": ((1, 256, 2, 64), (1, 256, 2, 64), 0, 0, False, "tpu", "pallas"),
+    "cpu": ((1, 256, 2, 64), (1, 256, 2, 64), 0, 0, False, "cpu", "blocked"),
+    "gqa": ((1, 256, 4, 64), (1, 256, 2, 64), 0, 0, False, "tpu", "blocked"),
+    "window": ((1, 256, 2, 64), (1, 256, 2, 64), 64, 0, False, "tpu",
+               "blocked"),
+    "mla": ((1, 256, 2, 96), (1, 256, 2, 64), 0, 0, False, "tpu", "blocked"),
+    "q_offset": ((1, 256, 2, 64), (1, 256, 2, 64), 0, 128, False, "tpu",
+                 "blocked"),
+    "seq_not_128": ((1, 200, 2, 64), (1, 200, 2, 64), 0, 0, False, "tpu",
+                    "blocked"),
+    "mesh": ((1, 256, 2, 64), (1, 256, 2, 64), 0, 0, True, "tpu", "blocked"),
+}
+
+
+@pytest.mark.parametrize("case", list(_DISPATCH))
+def test_attention_dispatch_rule_and_path_counter(case, monkeypatch):
+    """Only an unsharded MHA call on a TPU with no window or query offset,
+    Dk == Dv and Sq == Sk a multiple of 128 takes the kernel; the path counter
+    records which path each traced call took."""
+    from jax.sharding import Mesh
+
+    from repro.models import attention
+
+    q_shape, v_shape, window, q_offset, sharded, backend, path = \
+        _DISPATCH[case]
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",)) if sharded else None
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct(v_shape[:3] + q_shape[3:], jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(v_shape, jnp.bfloat16)
+    before = dict(attention.PATH_COUNTS)
+    out = jax.eval_shape(lambda q, k, v: attention.blocked_attention(
+        q, k, v, causal=True, window=window, q_offset=q_offset, kv_block=128,
+        mesh=mesh), q, k, v)
+    assert out.shape == q_shape[:3] + v_shape[3:]
+    counted = {p: attention.PATH_COUNTS[p] - before.get(p, 0)
+               for p in ("pallas", "blocked")}
+    assert counted == {p: int(p == path) for p in ("pallas", "blocked")}

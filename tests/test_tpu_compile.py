@@ -1,4 +1,5 @@
-"""The GMM kernels compile for a TPU v5e that is described, not attached.
+"""The GMM kernels and the attention kernel compile for a TPU v5e that is
+described, not attached.
 
 Interpret mode (tests/test_kernels.py) checks the kernels' math; only the
 chip's own compiler (Mosaic) checks that it accepts their layouts, and its
@@ -104,3 +105,29 @@ def test_gmm_kernel_carries_its_name(one_chip, lower, name):
     calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert calls and all(c.startswith(f"%{name}.") for c in calls), calls
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_attention_kernel_compiles_for_v5e(one_chip, grad):
+    """The train step's attention at GPT-2's shape (bf16 [8, 12, 1024, 64],
+    causal) compiles to the ``splash_mha_*`` kernels: the forward, and for the
+    gradient the forward with its residuals and one fused dq/dk/dv kernel."""
+    from repro.models.attention import _splash
+
+    def attend(q, k, v):
+        return _splash(q, k, v, True, 0.125)
+
+    if grad:
+        attend = jax.grad(
+            lambda *a, f=attend: jnp.sum(f(*a).astype(jnp.float32)),
+            argnums=(0, 1, 2))
+    x = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(attend).lower(x, x, x).compile().as_text()
+    calls = {line.split(" = ", 1)[0].strip().lstrip("%").split(".")[0]
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line}
+    want = {"splash_mha_fwd_no_residuals"}
+    if grad:
+        want = {"splash_mha_fwd_residuals", "splash_mha_dkv_no_residuals"}
+    assert calls == want, calls
